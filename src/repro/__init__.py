@@ -1,55 +1,5 @@
 """repro — JAX reproduction of distributed xPU stencil computations.
 
-Importing this package installs small forward-compatibility shims so the
-codebase (written against the current ``jax.shard_map`` API) also runs on
-older jax releases where ``shard_map`` lives in ``jax.experimental`` and
-takes ``check_rep`` instead of ``check_vma``:
-
-* ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-* ``jax.lax.pvary(x, axis_names)`` (identity where vma typing is absent)
-* ``jax.lax.axis_size(name)`` (via the static value of ``psum(1, name)``)
-* ``Compiled.cost_analysis()`` returning a dict (old jax returns ``[dict]``)
+Supports JAX 0.9.0 (``jax.shard_map``, ``jax.lax.axis_size``, and
+``Compiled.cost_analysis()`` returning a dict are used as-is).
 """
-
-from __future__ import annotations
-
-import functools
-
-import jax
-import jax.stages
-
-
-def _install_compat() -> None:
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=True, **kw):
-            kw.pop("check_rep", None)
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma, **kw)
-
-        functools.update_wrapper(shard_map, _shard_map)
-        jax.shard_map = shard_map
-
-    if not hasattr(jax.lax, "pvary"):
-        jax.lax.pvary = lambda x, axis_names=None: x
-
-    if not hasattr(jax.lax, "axis_size"):
-        # psum of a Python scalar is folded statically inside shard_map/pmap.
-        jax.lax.axis_size = lambda axis_name: jax.lax.psum(1, axis_name)
-
-    _cost = jax.stages.Compiled.cost_analysis
-    if not getattr(_cost, "_repro_compat", False):
-
-        def cost_analysis(self):
-            out = _cost(self)
-            if isinstance(out, list) and len(out) == 1:
-                return out[0]
-            return out
-
-        cost_analysis._repro_compat = True
-        jax.stages.Compiled.cost_analysis = cost_analysis
-
-
-_install_compat()

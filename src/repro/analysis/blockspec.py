@@ -29,7 +29,7 @@ proves, per blocked dimension:
 
 from __future__ import annotations
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from .findings import Finding
 from .jaxpr_walk import walk
@@ -58,7 +58,7 @@ def _image(bm, grid_points):
     cj = bm.index_map_jaxpr
     img = []
     for pt in grid_points:
-        res = jcore.eval_jaxpr(cj.jaxpr, cj.consts, *pt)
+        res = jcore.jaxpr_as_fun(cj)(*pt)
         img.append(tuple(int(r) for r in res))
     return img
 
@@ -110,12 +110,12 @@ def check_call(eqn, site: str) -> list[Finding]:
     for k, bm in enumerate(gm.block_mappings):
         is_output = k >= n_in
         role = f"out{k - n_in}" if is_output else f"in{k}"
-        shape = bm.array_shape_dtype.shape
+        shape = bm.array_aval.shape
         block = bm.block_shape
         nbs = []
         for d, b in enumerate(block):
             try:
-                b = int(b)
+                b = int(getattr(b, "block_size", b))  # pl.Blocked(n) or n
             except (TypeError, ValueError):
                 nbs.append(1)  # squeezed/mapped dim: treat as whole-dim
                 continue

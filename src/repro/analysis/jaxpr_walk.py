@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Iterator
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 # Collective primitives the congruence rule orders (the set JAX can emit
 # under shard_map for this codebase's topology layer).

@@ -47,7 +47,7 @@ import contextlib
 import threading
 from typing import Any, Iterator, Sequence
 
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax.interpreters import batching, mlir
 
 PRIMITIVE_NAME = "analysis_marker"

@@ -25,7 +25,7 @@ input of rank >= 2 — scalar bookkeeping psums are exempt):
 from __future__ import annotations
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from . import markers
 from .findings import Finding

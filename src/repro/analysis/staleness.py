@@ -32,7 +32,7 @@ back-to-back double exchange — a pure perf finding.
 
 from __future__ import annotations
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from . import markers
 from .findings import Finding

@@ -99,10 +99,13 @@ class Heat3D:
                             flight_dir=self.flight_dir,
                             meta={"app": "heat3d", "dims": self.grid.dims})
 
-    def oracle(self, nt: int) -> np.ndarray:
-        """Single-array NumPy reference on the deduplicated global grid."""
+    def oracle(self, nt: int, T0: np.ndarray | None = None) -> np.ndarray:
+        """Single-array NumPy reference on the deduplicated global grid,
+        from ``T0`` (a gathered global field; default the constant
+        :meth:`init_fields` value)."""
         g = self.grid
-        G = np.full(g.global_shape, 1.7, np.float64)
+        G = (np.full(g.global_shape, 1.7, np.float64) if T0 is None
+             else np.asarray(T0, np.float64))
         ci = 1.0 / self.c0
         a = self.dt * self.lam * ci
         for _ in range(nt):

@@ -48,7 +48,7 @@ class Poisson3D:
     heartbeat: int = 0      # rank-0 heartbeat event every k solver iterations
     flight_dir: str | None = None  # per-rank flight-record dump directory
     use_kernel: str = "auto"  # fused Pallas hot path: auto|pallas|interpret|ref
-    bx: int | None = None   # kernel x-block size (None = largest divisor <= 8)
+    bx: int | None = None   # kernel x-block size (None = auto, fits VMEM)
 
     def __post_init__(self):
         if self.dtype == jnp.float64 and not jax.config.jax_enable_x64:

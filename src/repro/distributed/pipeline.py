@@ -45,8 +45,8 @@ def gpipe(stage_fn, stage_params, microbatches, mesh, *, axis: str = "pod"):
             recv = jax.lax.ppermute(y, axis, perm)
             return recv, outs
 
-        recv0 = jax.lax.pvary(jnp.zeros_like(xs[0]), (axis,))
-        outs0 = jax.lax.pvary(jnp.zeros_like(xs), (axis,))
+        recv0 = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
         _, outs = jax.lax.fori_loop(0, M + S - 1, body, (recv0, outs0))
         # only the last stage holds real outputs; broadcast via psum of a
         # one-hot mask (cheap relative to the pipeline itself)

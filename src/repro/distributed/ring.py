@@ -64,9 +64,9 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = True,
         return kb, vb, acc, m_new, l
 
     # mark the accumulators device-varying for shard_map's vma typing
-    acc = jax.lax.pvary(jnp.zeros((B, Hkv, g, T, D), jnp.float32), (axis_name,))
-    m = jax.lax.pvary(jnp.full((B, Hkv, g, T, 1), -1e30, jnp.float32), (axis_name,))
-    l = jax.lax.pvary(jnp.zeros((B, Hkv, g, T, 1), jnp.float32), (axis_name,))
+    acc = jax.lax.pcast(jnp.zeros((B, Hkv, g, T, D), jnp.float32), (axis_name,), to="varying")
+    m = jax.lax.pcast(jnp.full((B, Hkv, g, T, 1), -1e30, jnp.float32), (axis_name,), to="varying")
+    l = jax.lax.pcast(jnp.zeros((B, Hkv, g, T, 1), jnp.float32), (axis_name,), to="varying")
     _, _, acc, m, l = jax.lax.fori_loop(0, n, body, (k, v, acc, m, l))
     out = acc / jnp.where(l == 0.0, 1.0, l)
     return out.reshape(B, H, T, D).astype(q.dtype)

@@ -105,72 +105,77 @@ def _center_au(ue, ce, h2):
     return u0, -acc
 
 
-def _apply_center(i, cur, ue, ce, *, bx, nx, h2):
+def _apply_center(i, ue, ce, *, bx, nx, h2):
     _, au = _center_au(ue, ce, h2)
-    interior = _xmask(i, bx, nx)
-    return jnp.zeros_like(cur).at[_IN3].set(
-        jnp.where(interior, au, 0.0).astype(cur.dtype))
+    return jnp.where(_xmask(i, bx, nx), au, 0.0)
 
 
-def _residual_center(i, cur, ue, ce, f, *, bx, nx, h2):
+def _residual_center(i, ue, ce, f, *, bx, nx, h2):
     _, au = _center_au(ue, ce, h2)
-    r = f[_IN3] - au
-    interior = _xmask(i, bx, nx)
-    return jnp.zeros_like(cur).at[_IN3].set(
-        jnp.where(interior, r, 0.0).astype(cur.dtype))
+    return jnp.where(_xmask(i, bx, nx), f[_IN3] - au, 0.0)
 
 
-def _jacobi_center(i, cur, ue, ce, f, dia, *, bx, nx, h2, omega):
+def _jacobi_center(i, ue, ce, f, dia, *, bx, nx, h2, omega):
     u0, au = _center_au(ue, ce, h2)
     r = f[_IN3] - au
     new = u0 + omega * r / dia[_IN3]
-    interior = _xmask(i, bx, nx)
-    return cur.at[_IN3].set(jnp.where(interior, new, u0).astype(cur.dtype))
+    return jnp.where(_xmask(i, bx, nx), new, u0)
 
 
-def _cheb_center(i, cur, ue, ce, f, dia, d, *, bx, nx, h2, a, b):
+def _cheb_center(i, ue, ce, f, dia, d, *, bx, nx, h2, a, b):
     u0, au = _center_au(ue, ce, h2)
     z = (f[_IN3] - au) / dia[_IN3]
     dn = z / b if a is None else a * d[_IN3] + b * z
     interior = _xmask(i, bx, nx)
-    u_new = cur.at[_IN3].set(
-        jnp.where(interior, u0 + dn, u0).astype(cur.dtype))
-    d_new = jnp.zeros_like(cur).at[_IN3].set(
-        jnp.where(interior, dn, 0.0).astype(cur.dtype))
-    return u_new, d_new
+    return jnp.where(interior, u0 + dn, u0), jnp.where(interior, dn, 0.0)
+
+
+# The center functions above return the (y, z) interior of the block;
+# the ring is the input block (u updates) or zero (A u, residual, d).
+# In the kernels the two parts are two stores into the output ref: a
+# value scatter (``.at[].set``) has no Mosaic lowering.
+
+def _put(base, inner):
+    """``base`` with its (y, z) interior replaced by ``inner`` (eager)."""
+    return base.at[_IN3].set(inner.astype(base.dtype))
+
+
+def _store(ref, base, inner):
+    ref[...] = base
+    ref[_IN3] = inner.astype(ref.dtype)
 
 
 def _apply_center_kernel(pu, cu, nu, pc, cc, nc, out_ref, *, bx, nx, h2):
     cur = cu[...]
-    out_ref[...] = _apply_center(
-        pl.program_id(0), cur, _ext(pu, cur, nu), _ext(pc, cc[...], nc),
-        bx=bx, nx=nx, h2=h2)
+    _store(out_ref, jnp.zeros_like(cur), _apply_center(
+        pl.program_id(0), _ext(pu, cur, nu), _ext(pc, cc[...], nc),
+        bx=bx, nx=nx, h2=h2))
 
 
 def _residual_center_kernel(pu, cu, nu, pc, cc, nc, f_ref, out_ref, *, bx,
                             nx, h2):
     cur = cu[...]
-    out_ref[...] = _residual_center(
-        pl.program_id(0), cur, _ext(pu, cur, nu), _ext(pc, cc[...], nc),
-        f_ref[...], bx=bx, nx=nx, h2=h2)
+    _store(out_ref, jnp.zeros_like(cur), _residual_center(
+        pl.program_id(0), _ext(pu, cur, nu), _ext(pc, cc[...], nc),
+        f_ref[...], bx=bx, nx=nx, h2=h2))
 
 
 def _jacobi_center_kernel(pu, cu, nu, pc, cc, nc, f_ref, dia_ref, out_ref,
                           *, bx, nx, h2, omega):
     cur = cu[...]
-    out_ref[...] = _jacobi_center(
-        pl.program_id(0), cur, _ext(pu, cur, nu), _ext(pc, cc[...], nc),
-        f_ref[...], dia_ref[...], bx=bx, nx=nx, h2=h2, omega=omega)
+    _store(out_ref, cur, _jacobi_center(
+        pl.program_id(0), _ext(pu, cur, nu), _ext(pc, cc[...], nc),
+        f_ref[...], dia_ref[...], bx=bx, nx=nx, h2=h2, omega=omega))
 
 
 def _cheb_center_kernel(pu, cu, nu, pc, cc, nc, f_ref, dia_ref, d_ref,
                         u_out, d_out, *, bx, nx, h2, a, b):
     cur = cu[...]
-    u_new, d_new = _cheb_center(
-        pl.program_id(0), cur, _ext(pu, cur, nu), _ext(pc, cc[...], nc),
+    u_in, d_in = _cheb_center(
+        pl.program_id(0), _ext(pu, cur, nu), _ext(pc, cc[...], nc),
         f_ref[...], dia_ref[...], d_ref[...], bx=bx, nx=nx, h2=h2, a=a, b=b)
-    u_out[...] = u_new
-    d_out[...] = d_new
+    _store(u_out, cur, u_in)
+    _store(d_out, jnp.zeros_like(cur), d_in)
 
 
 # ---------------------------------------------------------------------------
@@ -399,20 +404,22 @@ def blocked_ref(op: str, u, c, f=None, dia=None, d=None, *, h2, sd=None,
         ue = _ext(blk(u, p), cur, blk(u, n))
         ce = _ext(blk(c, p), blk(c, i), blk(c, n))
         if sd is None:
+            zero = jnp.zeros_like(cur)
             if op == "apply":
-                outs.append(_apply_center(i, cur, ue, ce, bx=bx, nx=nx,
-                                          h2=h2))
+                outs.append(_put(zero, _apply_center(i, ue, ce, bx=bx, nx=nx,
+                                                     h2=h2)))
             elif op == "residual":
-                outs.append(_residual_center(i, cur, ue, ce, blk(f, i),
-                                             bx=bx, nx=nx, h2=h2))
+                outs.append(_put(zero, _residual_center(
+                    i, ue, ce, blk(f, i), bx=bx, nx=nx, h2=h2)))
             elif op == "jacobi":
-                outs.append(_jacobi_center(i, cur, ue, ce, blk(f, i),
-                                           blk(dia, i), bx=bx, nx=nx, h2=h2,
-                                           omega=omega))
+                outs.append(_put(cur, _jacobi_center(
+                    i, ue, ce, blk(f, i), blk(dia, i), bx=bx, nx=nx, h2=h2,
+                    omega=omega)))
             elif op == "cheb":
-                outs.append(_cheb_center(i, cur, ue, ce, blk(f, i),
-                                         blk(dia, i), blk(d, i), bx=bx,
-                                         nx=nx, h2=h2, a=a, b=b))
+                u_in, d_in = _cheb_center(i, ue, ce, blk(f, i), blk(dia, i),
+                                          blk(d, i), bx=bx, nx=nx, h2=h2,
+                                          a=a, b=b)
+                outs.append((_put(cur, u_in), _put(zero, d_in)))
             else:
                 raise ValueError(f"unknown op={op!r}")
         else:

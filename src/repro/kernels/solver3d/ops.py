@@ -2,7 +2,8 @@
 
 Every entry point takes ``use_kernel="auto"|"pallas"|"interpret"|"ref"``
 and an optional x-block size ``bx`` (None auto-picks the largest divisor
-``<= 8`` of the local x extent), resolves them once through
+``<= 8`` of the local x extent whose working set fits VMEM), resolves
+them once through
 :func:`repro.kernels.dispatch.resolve` (graceful ``ref`` fallback on
 auto, hard error on an explicit kernel request that cannot run) and
 calls either the Pallas kernel (:mod:`.kernel`) or the canonical
@@ -34,7 +35,7 @@ def _h2(spacing) -> tuple:
     return tuple(float(s) ** 2 for s in spacing)
 
 
-def _resolve(use_kernel, u, bx, loc, imask, where, needs_mask=True):
+def _resolve(use_kernel, u, bx, loc, imask, where, op, needs_mask=True):
     sd = _loc.stagger_dim(loc)
     if sd is not None and needs_mask and imask is None:
         raise ValueError(f"{where}: loc={loc!r} needs the interior mask "
@@ -43,7 +44,9 @@ def _resolve(use_kernel, u, bx, loc, imask, where, needs_mask=True):
     if u.ndim != 3:
         unsupported = f"a {u.ndim}-D field (kernels are 3-D)"
     impl, nbx = _dispatch.resolve(use_kernel, shape=u.shape, dtype=u.dtype,
-                                  bx=bx, unsupported=unsupported, where=where)
+                                  bx=bx, unsupported=unsupported, where=where,
+                                  blocks=_dispatch.VMEM_BLOCKS[
+                                      op if sd is None else op + "_face"])
     return sd, impl, nbx
 
 
@@ -52,7 +55,7 @@ def apply_op(u, c, *, spacing, loc: str = "center", use_kernel: str = "auto",
     """Fused ``A u`` (center: interior stencil, zero ring; face: raw
     unmasked roll-form stencil — callers mask, as in the cycle)."""
     sd, impl, nbx = _resolve(use_kernel, u, bx, loc, None,
-                             "solver3d.apply_op", needs_mask=False)
+                             "solver3d.apply_op", "apply", needs_mask=False)
     if impl == "ref":
         return ref.apply_op_ref(u, c, spacing, loc)
     return _k.apply_pallas(u, c, h2=_h2(spacing), sd=sd, bx=nbx,
@@ -63,7 +66,7 @@ def residual_op(u, c, f, *, spacing, loc: str = "center", imask=None,
                 use_kernel: str = "auto", bx: int | None = None):
     """Fused ``f - A u`` on the location's unknowns, zero elsewhere."""
     sd, impl, nbx = _resolve(use_kernel, u, bx, loc, imask,
-                             "solver3d.residual_op")
+                             "solver3d.residual_op", "residual")
     if impl == "ref":
         return ref.residual_op_ref(u, c, f, spacing, loc, imask)
     return _k.residual_pallas(u, c, f, h2=_h2(spacing), sd=sd, imask=imask,
@@ -76,7 +79,7 @@ def jacobi_sweep(u, c, f, dia, *, omega, spacing, loc: str = "center",
     (stencil + residual + diagonal scale + axpy in one kernel pass; no
     halo update — the caller owns communication)."""
     sd, impl, nbx = _resolve(use_kernel, u, bx, loc, imask,
-                             "solver3d.jacobi_sweep")
+                             "solver3d.jacobi_sweep", "jacobi")
     if impl == "ref":
         return ref.jacobi_sweep_ref(u, c, f, dia, omega=omega,
                                     spacing=spacing, loc=loc, imask=imask)
@@ -94,7 +97,7 @@ def cheb_sweep(u, c, f, dia, d, *, a, b, spacing, loc: str = "center",
     ``b = 2 rho_k / delta`` — matching ``make_v_cycle`` exactly.
     """
     sd, impl, nbx = _resolve(use_kernel, u, bx, loc, imask,
-                             "solver3d.cheb_sweep")
+                             "solver3d.cheb_sweep", "cheb")
     if impl == "ref":
         return ref.cheb_sweep_ref(u, c, f, dia, d, a=a, b=b, spacing=spacing,
                                   loc=loc, imask=imask)

@@ -23,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _heat_kernel(prev_ref, cur_ref, nxt_ref, ci_ref, coef_ref, out_ref, *, bx: int, nx: int):
@@ -54,9 +55,10 @@ def _heat_kernel(prev_ref, cur_ref, nxt_ref, ci_ref, coef_ref, out_ref, *, bx: i
     interior = (gx >= 1) & (gx <= nx - 2)
     new = jnp.where(interior, new, c)
 
-    out = cur
-    out = out.at[:, 1:-1, 1:-1].set(new.astype(out.dtype))
-    out_ref[...] = out
+    # Pass-through ring, then the interior: two stores, because a value
+    # scatter (``.at[].set``) has no Mosaic lowering.
+    out_ref[...] = cur
+    out_ref[:, 1:-1, 1:-1] = new.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bx", "interpret"))
@@ -85,7 +87,7 @@ def heat_step_pallas(T, Ci, lam, dt, dx, dy, dz, *, bx: int = 8, interpret: bool
     cur_spec = pl.BlockSpec(block, lambda i: (i, 0, 0))
     nxt_spec = pl.BlockSpec(block, lambda i: ((i + 1) % nb, 0, 0))
 
-    coef_spec = pl.BlockSpec((4,), lambda i: (0,))
+    coef_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     return pl.pallas_call(
         functools.partial(_heat_kernel, bx=bx, nx=nx),

@@ -24,13 +24,14 @@ def heat_step(T, Ci, lam, dt, dx, dy, dz, *, use_kernel: str = "auto",
               bx: int | None = None):
     """One stencil step. ``use_kernel``: 'auto' | 'pallas' | 'interpret' |
     'ref'; ``bx`` is the x-block extent (None auto-picks the largest
-    divisor of the local extent ``<= 8``)."""
+    divisor of the local extent ``<= 8`` that fits VMEM)."""
     unsupported = None
     if T.ndim != 3:
         unsupported = f"a {T.ndim}-D field (kernels are 3-D)"
     impl, nbx = _dispatch.resolve(use_kernel, shape=T.shape, dtype=T.dtype,
                                   bx=bx, unsupported=unsupported,
-                                  where="stencil3d.heat_step")
+                                  where="stencil3d.heat_step",
+                                  blocks=_dispatch.VMEM_BLOCKS["heat"])
     # Ghost-demand contract for the static analyzer (identity; binds
     # only under an analysis trace).  Marked HERE — outside the jitted
     # kernel wrapper — so the pjit cache never sees a marker trace.

@@ -49,7 +49,9 @@ def run_one(arch: str, shape: str, multi_pod: bool, out_path: str | None = None,
         if mesh_shape:  # supplementary meshes, e.g. "8x16x16" = 2048 chips
             dims = tuple(int(x) for x in mesh_shape.split("x"))
             axes = ("pod", "data", "model")[-len(dims):]
-            mesh = jax.make_mesh(dims, axes)
+            mesh = jax.make_mesh(
+                dims, axes,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         nchips = mesh.size

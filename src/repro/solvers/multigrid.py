@@ -145,7 +145,8 @@ def poisson_apply(grid: ImplicitGlobalGrid, u, c, spacing,
         unsupported = f"a {u.ndim}-D field (kernels are 3-D)"
     impl, nbx = _dispatch.resolve(use_kernel, shape=u.shape, dtype=u.dtype,
                                   bx=bx, unsupported=unsupported,
-                                  where="multigrid.poisson_apply")
+                                  where="multigrid.poisson_apply",
+                                  blocks=_dispatch.VMEM_BLOCKS["apply"])
     if impl != "ref":
         if update_halo:
             u = grid.update_halo(u)
@@ -354,12 +355,15 @@ def make_v_cycle(
         unsupported = "Helmholtz shifts"
     elif nd != 3:
         unsupported = f"a {nd}-D hierarchy (kernels are 3-D)"
+    suffix = "" if sd is None else "_face"
+    blocks = max(_dispatch.VMEM_BLOCKS[op + suffix]
+                 for op in ("residual", "jacobi", "cheb"))
     impls, bxs = [], []
     for k, g in enumerate(grids):
         impl_k, bx_k = _dispatch.resolve(
             use_kernel, shape=g.local_shape, dtype=cs[0].dtype,
             bx=bx if k == 0 else None, unsupported=unsupported,
-            where=f"multigrid.v_cycle[level {k}]")
+            where=f"multigrid.v_cycle[level {k}]", blocks=blocks)
         impls.append(impl_k)
         bxs.append(bx_k)
     fused_any = any(i != "ref" for i in impls)

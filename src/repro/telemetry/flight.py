@@ -43,6 +43,7 @@ import signal
 import time
 
 from .sink import NullSink
+from .timers import host_rank
 
 _CURRENT: "FlightRecorder | None" = None
 
@@ -97,14 +98,13 @@ class FlightRecorder:
         self.capacity = int(capacity)
         self.meta = dict(meta or {})
         self.epoch = time.time()
-        try:
-            import jax
-            self.host_rank = jax.process_index()
-        except Exception:
-            self.host_rank = 0
         self._buffers: dict[int, collections.deque] = {}
         self.dump_count = 0
         self.dumped_paths: list[str] = []
+
+    @property
+    def host_rank(self) -> int:
+        return host_rank()
 
     def record(self, event: dict, rank: int | None = None):
         # route by the event's own rank (device callbacks stamp it) so

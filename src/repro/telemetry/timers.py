@@ -23,6 +23,21 @@ import time
 from .sink import MemorySink, NullSink
 
 
+def host_rank() -> int:
+    """This host's process index, read only from a backend that is
+    already up (0 before then).  ``jax.process_index()`` would start a
+    backend itself, and on a chip host that claims the chip for this
+    process, so a parent that only builds a session must never call it.
+    """
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return 0
+    import jax
+
+    return jax.process_index()
+
+
 class Session:
     """An active telemetry session: clock origin + sink + span stack."""
 
@@ -31,11 +46,10 @@ class Session:
         self.meta = dict(meta or {})
         self.t0 = time.perf_counter()
         self._depth = 0
-        try:
-            import jax
-            self.rank = jax.process_index()
-        except Exception:  # jax not initialized yet — single host
-            self.rank = 0
+
+    @property
+    def rank(self) -> int:
+        return host_rank()
 
     # -- event emission ------------------------------------------------
     def now(self) -> float:

@@ -20,7 +20,7 @@ PRELUDE = """
 import os
 os.environ["XLA_FLAGS"] = (
     os.environ.get("_REPRO_XLA_EXTRA", "")
-    + " --xla_force_host_platform_device_count={ndev}"
+    + " --xla_force_host_platform_device_count={ndev} {xla_flags}"
 )
 import jax
 jax.config.update("jax_platform_name", "cpu")
@@ -29,13 +29,16 @@ import jax.numpy as jnp
 """
 
 
-def run(snippet: str, ndev: int = 8, timeout: int = 600) -> str:
+def run(snippet: str, ndev: int = 8, timeout: int = 600,
+        xla_flags: str = "") -> str:
     """Execute ``snippet`` with ``ndev`` devices; returns stdout.
 
     The snippet should use plain ``assert``/prints; a non-zero exit fails
-    the calling test with full output attached.
+    the calling test with full output attached.  ``xla_flags`` are
+    appended to the subprocess's ``XLA_FLAGS``.
     """
-    code = PRELUDE.format(ndev=ndev) + textwrap.dedent(snippet)
+    code = PRELUDE.format(ndev=ndev, xla_flags=xla_flags) \
+        + textwrap.dedent(snippet)
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

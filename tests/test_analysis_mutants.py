@@ -119,7 +119,6 @@ def test_mutant_read_deeper_than_halo_caught():
 
 def test_mutants_collective_congruence_caught():
     run("""
-import repro  # shard_map shim
 from jax.sharding import PartitionSpec as P
 from repro import analysis
 

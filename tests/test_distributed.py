@@ -89,7 +89,7 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed.seqpar import seq_ssd_scan
 from repro.kernels.ssd import ssd_ref
 
-mesh = jax.make_mesh((8,), ("sp",))
+mesh = jax.make_mesh((8,), ("sp",), axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.RandomState(3)
 Ba, T, H, G, N, Pd = 2, 64, 4, 1, 8, 16
 x = jnp.asarray(rng.randn(Ba, T, H, Pd), jnp.float32)

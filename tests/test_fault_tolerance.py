@@ -114,7 +114,8 @@ def run_steps(params, opt, steps, rules, start=0):
 pr, orr, loss_ref = run_steps(params0, opt0, 4, None)
 
 # mesh A: 2 steps, checkpoint
-meshA = jax.make_mesh((4, 2), ("data", "model"))
+meshA = jax.make_mesh((4, 2), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rulesA = default_rules(meshA, batch_size=8)
 pA = jax.tree.map(jax.device_put, params0, pm.shardings(specs, rulesA))
 p1, o1, _ = run_steps(pA, opt0, 2, rulesA)
@@ -122,7 +123,8 @@ with tempfile.TemporaryDirectory() as d:
     ckpt.save({"params": p1, "opt": o1}, 2, d)
 
     # mesh B (elastic change): restore with B shardings, run 2 more
-    meshB = jax.make_mesh((2, 4), ("data", "model"))
+    meshB = jax.make_mesh((2, 4), ("data", "model"),
+                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rulesB = default_rules(meshB, batch_size=8)
     shardB = {"params": pm.shardings(specs, rulesB),
               "opt": optim.state_shardings(specs, tcfg.opt, rulesB)}

@@ -312,11 +312,73 @@ def test_explicit_kernel_raises():
 
 
 def test_pick_bx():
-    assert dispatch.pick_bx(8) == 8
-    assert dispatch.pick_bx(12) == 6
-    assert dispatch.pick_bx(7) == 7
-    assert dispatch.pick_bx(13) is None   # prime above the limit
-    assert dispatch.pick_bx(1) is None
+    assert dispatch.pick_bx((8, 8, 8)) == 8
+    assert dispatch.pick_bx((12, 8, 8)) == 6
+    assert dispatch.pick_bx((7, 8, 8)) == 7
+    assert dispatch.pick_bx((13, 8, 8)) is None   # prime above the limit
+    assert dispatch.pick_bx((1, 8, 8)) is None
+
+
+@pytest.mark.parametrize("shape, kernel, itemsize, want", [
+    # small planes: VMEM never binds, the largest divisor <= 8 wins
+    ((34, 34, 34), "heat", 4, 2),
+    ((32, 34, 34), "cheb", 4, 8),
+    # 256^2 f32: 16 blocks of bx=4 rows fill the 16 MiB exactly
+    ((256, 256, 256), "heat", 4, 4),
+    ((256, 256, 256), "heat", 2, 8),      # bf16 halves the block
+    ((18, 256, 256), "heat", 4, 3),       # hide_communication x-slab
+    ((130, 130, 130), "cheb", 4, 2),
+    ((130, 130, 130), "apply", 4, 5),
+    # 512^2 f32: even bx=2 overflows — no block fits
+    ((512, 512, 512), "heat", 4, None),
+    ((16, 512, 512), "jacobi", 4, None),
+])
+def test_pick_bx_fits_vmem(shape, kernel, itemsize, want):
+    blocks = dispatch.VMEM_BLOCKS[kernel]
+    assert dispatch.pick_bx(shape, itemsize, blocks) == want
+    if want is not None:
+        assert blocks * dispatch.block_bytes(want, *shape[1:], itemsize) \
+            <= dispatch.VMEM_LIMIT_BYTES
+
+
+def test_block_bytes_pads_to_tiles():
+    # (y, z) planes pad to (8, 128) f32 tiles and (16, 128) bf16 tiles
+    assert dispatch.block_bytes(1, 130, 130, 4) == 136 * 256 * 4
+    assert dispatch.block_bytes(2, 130, 130, 2) == 2 * 144 * 256 * 2
+    assert dispatch.block_bytes(4, 256, 256, 4) == 1 << 20
+
+
+def test_auto_plane_too_large_warns_once_and_falls_back():
+    """On a TPU a 512^2 plane has no block that fits VMEM: ``auto``
+    warns once and takes the reference, an explicit ``pallas`` raises,
+    and ``interpret`` (no VMEM) still runs with a one-row block."""
+    dispatch.reset_warnings()
+    args = dict(shape=(512, 512, 512), dtype=jnp.float32, backend="tpu",
+                where="test.wide", blocks=dispatch.VMEM_BLOCKS["heat"])
+    with pytest.warns(RuntimeWarning, match="VMEM"):
+        assert dispatch.resolve("auto", **args) == ("ref", None)
+    with warnings.catch_warnings():  # second hit: silent
+        warnings.simplefilter("error")
+        assert dispatch.resolve("auto", **args) == ("ref", None)
+    # an explicit block that overflows is refused by auto too
+    dispatch.reset_warnings()
+    with pytest.warns(RuntimeWarning, match="VMEM"):
+        assert dispatch.resolve("auto", **{**args, "shape": (256, 256, 256),
+                                           "bx": 8}) == ("ref", None)
+    with pytest.raises(ValueError, match="VMEM"):
+        dispatch.resolve("pallas", **args)
+    assert dispatch.resolve("interpret", **args) == ("interpret", 1)
+    dispatch.reset_warnings()
+
+
+def test_recording_lists_every_resolution():
+    with dispatch.recording() as rec:
+        dispatch.resolve("ref", shape=(8, 8, 8), dtype=jnp.float32,
+                         where="a")
+        dispatch.resolve("auto", shape=(8, 8, 8), dtype=jnp.float32,
+                         backend="tpu", where="b")
+    assert rec == [("a", (8, 8, 8), "ref", None),
+                   ("b", (8, 8, 8), "pallas", 8)]
 
 
 # ---------------------------------------------------------------------------

@@ -240,8 +240,11 @@ def test_transfer_adjointness_per_location(shape, loc, seed):
         u[tuple(edge)] = 0.0
         edge[d] = np.array([0, cshape[d] - 1])
         v[tuple(edge)] = 0.0
-    lhs = float((np.asarray(transfers.restrict(jnp.asarray(u), loc)) * v).sum())
-    rhs = float((u * np.asarray(transfers.prolong(jnp.asarray(v), loc))).sum()) / 8.0
+    with jax.enable_x64(True):  # the 1e-12 bound is an f64 bound
+        R_u = np.asarray(transfers.restrict(jnp.asarray(u), loc))
+        P_v = np.asarray(transfers.prolong(jnp.asarray(v), loc))
+    lhs = float((R_u * v).sum())
+    rhs = float((u * P_v).sum()) / 8.0
     scale = np.linalg.norm(u) * np.linalg.norm(v) + 1.0
     assert abs(lhs - rhs) <= 1e-12 * scale, (lhs, rhs)
 

@@ -261,6 +261,24 @@ def test_observe_composes_flight_and_watch(tmp_path):
     assert flight_current() is None and not tele.watching()
 
 
+def test_constructors_start_no_backend():
+    """Building a session or a flight recorder must not start a JAX
+    backend: on a chip host that would claim the chip for a parent that
+    only wants to launch workers.  The rank is read once one is up."""
+    run("""
+from jax._src import xla_bridge
+from repro import telemetry as tele
+
+s = tele.Session()
+rec = tele.FlightRecorder("unused")
+assert s.rank == 0 and rec.host_rank == 0
+assert not xla_bridge.backends_are_initialized()
+jax.devices()
+assert s.rank == jax.process_index() and rec.host_rank == s.rank
+print("OK")
+""", ndev=1)
+
+
 def test_region_noop_without_session():
     from repro.telemetry import current_session, enabled, region
 

@@ -227,6 +227,11 @@ except ValueError as e:
 print("OK")
 """,
         ndev=8,
+        # Bitwise across slab shapes needs FMA-free codegen: XLA:CPU
+        # contracts a*b+c to an FMA in some loop shapes and not others,
+        # so the hidden step's 4-wide z-slabs would round differently
+        # from the full-width step on an FMA-capable host.
+        xla_flags="--xla_cpu_max_isa=AVX",
     )
 
 
