@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.telemetry.timers import trace_span
+
 from . import halo as _halo
 from . import hide as _hide
 from .topology import CartesianTopology, make_grid_mesh
@@ -191,10 +193,15 @@ class ImplicitGlobalGrid:
         ``_staggered_tree``) are sharded leaf-wise via a spec prefix;
         everything else is replicated.  All outputs are treated as grid
         fields (or pytrees thereof).
+
+        Each call runs inside a profiler span ``grid.parallel.<fn name>``
+        (:func:`repro.telemetry.trace_span`), and ``wrapper.lower(*args)``
+        lowers the jitted program such a call runs.
         """
 
-        @functools.wraps(fn)
-        def wrapper(*args):
+        span = f"grid.parallel.{fn.__name__}"
+
+        def jitted(args):
             args = tuple(
                 a if hasattr(a, "ndim") or getattr(a, "_staggered_tree", False)
                 else jnp.asarray(a)
@@ -223,8 +230,23 @@ class ImplicitGlobalGrid:
                     check_vma=False,
                 )
                 self._jit_cache[key] = jax.jit(sm)
-            return self._jit_cache[key](*args)
+            return self._jit_cache[key], args
 
+        @functools.wraps(fn)
+        def wrapper(*args):
+            # One span per call on the profiler's timeline: the host's time
+            # in the call, its dispatch and any wait for an execution slot.
+            with trace_span(span):
+                f, args = jitted(args)
+                return f(*args)
+
+        def lower(*args):
+            """The lowering of the jitted program a call with ``args``
+            runs (``.compile().as_text()`` for its compiled module)."""
+            f, args = jitted(args)
+            return f.lower(*args)
+
+        wrapper.lower = lower
         return wrapper
 
     # Local-view operations, re-exported with the grid's topology bound:

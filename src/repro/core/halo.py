@@ -115,7 +115,10 @@ def update_halo(
             # is identical with or without it.
             _record_halo(A.shape, d + off, width,
                          jnp.dtype(A.dtype).itemsize)
-            A = _update_one_dim(topo, A, d, d + off, width)
+            # Named scope: the exchange's ops carry "halo.update" in their
+            # ``op_name`` metadata (trace-time only).
+            with jax.named_scope("halo.update"):
+                A = _update_one_dim(topo, A, d, d + off, width)
             exchanged.append(d)
         A = _an.exchange_out(A, width=width, site="core.halo.update_halo",
                              dims=exchanged)

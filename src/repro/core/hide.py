@@ -76,36 +76,45 @@ def hide_communication(
         res = step_fn(*slabs)
         return tuple(res) if isinstance(res, (tuple, list)) else (res,)
 
+    # Each phase runs under a named scope ("hide.shell", "hide.exchange",
+    # "hide.interior"): the compiled ops carry it in their ``op_name``
+    # metadata, so a profiler trace can be read phase by phase
+    # (``repro.telemetry.op_scopes``).  Scopes are trace-time only.
+
     # ---- 1. boundary shell: two face slabs per grid dim ----------------
     # Slabs span the full extent of the other dims; corners are recomputed
     # by later faces (same values — harmless).
     outs = None
-    for d in range(nd):
-        n = shape[d]
-        wd = w[d]
-        lo = run(tuple(A[_slc(nd, d, 0, 2 * h + wd)] for A in inputs))
-        hi = run(tuple(A[_slc(nd, d, n - 2 * h - wd, n)] for A in inputs))
-        if outs is None:
-            # Pass-through convention: output k starts as old inputs[k].
-            outs = [inputs[k] for k in range(len(lo))]
-        sl = _slc(nd, d, h, h + wd)  # valid region, slab-local == face-global (low)
-        for k in range(len(outs)):
-            outs[k] = outs[k].at[sl].set(lo[k][sl])
-            outs[k] = outs[k].at[_slc(nd, d, n - h - wd, n - h)].set(
-                hi[k][_slc(nd, d, h, h + wd)]
-            )
+    with jax.named_scope("hide.shell"):
+        for d in range(nd):
+            n = shape[d]
+            wd = w[d]
+            lo = run(tuple(A[_slc(nd, d, 0, 2 * h + wd)] for A in inputs))
+            hi = run(tuple(A[_slc(nd, d, n - 2 * h - wd, n)] for A in inputs))
+            if outs is None:
+                # Pass-through convention: output k starts as old inputs[k].
+                outs = [inputs[k] for k in range(len(lo))]
+            sl = _slc(nd, d, h, h + wd)  # valid region, slab-local == face-global (low)
+            for k in range(len(outs)):
+                outs[k] = outs[k].at[sl].set(lo[k][sl])
+                outs[k] = outs[k].at[_slc(nd, d, n - h - wd, n - h)].set(
+                    hi[k][_slc(nd, d, h, h + wd)]
+                )
 
     # ---- 2. halo exchange — depends only on the boundary shell ---------
-    updated = update_halo(topo, *outs, width=h)
+    with jax.named_scope("hide.exchange"):
+        updated = update_halo(topo, *outs, width=h)
     outs = list(updated) if isinstance(updated, tuple) else [updated]
 
     # ---- 3. interior — independent of the collectives (overlappable) ---
-    int_in = tuple(A[tuple(slice(w[d], shape[d] - w[d]) for d in range(nd))] for A in inputs)
-    int_out = run(int_in)
-    sl_local = tuple(slice(h, (shape[d] - 2 * w[d]) - h) for d in range(nd))
-    sl_global = tuple(slice(w[d] + h, shape[d] - w[d] - h) for d in range(nd))
-    for k in range(len(outs)):
-        outs[k] = outs[k].at[sl_global].set(int_out[k][sl_local])
+    with jax.named_scope("hide.interior"):
+        int_in = tuple(A[tuple(slice(w[d], shape[d] - w[d]) for d in range(nd))]
+                       for A in inputs)
+        int_out = run(int_in)
+        sl_local = tuple(slice(h, (shape[d] - 2 * w[d]) - h) for d in range(nd))
+        sl_global = tuple(slice(w[d] + h, shape[d] - w[d] - h) for d in range(nd))
+        for k in range(len(outs)):
+            outs[k] = outs[k].at[sl_global].set(int_out[k][sl_local])
 
     # Analyzer contract: semantically this IS ``update_halo(step(...))``
     # (bitwise-pinned in tests) — the exchanged planes mirror the

@@ -296,7 +296,7 @@ def apply_pallas(u, c, *, h2, sd=None, bx: int, interpret: bool = False):
         in_specs=[prev, cur, nxt, prev, cur, nxt],
         out_specs=cur,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        interpret=interpret,
+        interpret=interpret, name="solver3d_apply",
     )(u, u, u, c, c, c)
 
 
@@ -319,7 +319,7 @@ def residual_pallas(u, c, f, *, h2, sd=None, imask=None, bx: int,
     return pl.pallas_call(
         kern, grid=(nb,), in_specs=in_specs, out_specs=cur,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        interpret=interpret,
+        interpret=interpret, name="solver3d_residual",
     )(*args)
 
 
@@ -345,7 +345,7 @@ def jacobi_pallas(u, c, f, dia, *, omega, h2, sd=None, imask=None, bx: int,
     return pl.pallas_call(
         kern, grid=(nb,), in_specs=in_specs, out_specs=cur,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        interpret=interpret,
+        interpret=interpret, name="solver3d_jacobi",
     )(*args)
 
 
@@ -371,7 +371,7 @@ def cheb_pallas(u, c, f, dia, d, *, a, b, h2, sd=None, imask=None, bx: int,
                  jax.ShapeDtypeStruct(u.shape, u.dtype)]
     return pl.pallas_call(
         kern, grid=(nb,), in_specs=in_specs, out_specs=[cur, cur],
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, interpret=interpret, name="solver3d_cheb",
     )(*args)
 
 

@@ -95,6 +95,7 @@ def ssd_intra_chunk_pallas(x, dt, A, B, C, *, chunk: int = 64, interpret: bool =
             jax.ShapeDtypeStruct((Ba * H * nc, N, P), jnp.float32),
         ],
         interpret=interpret,
+        name="ssd_intra_chunk",
     )(xc, Bc, Cc, dtc, sc)
 
     y_diag = (

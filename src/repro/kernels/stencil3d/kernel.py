@@ -96,4 +96,5 @@ def heat_step_pallas(T, Ci, lam, dt, dx, dy, dz, *, bx: int = 8, interpret: bool
         out_specs=cur_spec,
         out_shape=jax.ShapeDtypeStruct(T.shape, T.dtype),
         interpret=interpret,
+        name="stencil3d_heat",
     )(T, T, T, Ci, coef)

@@ -142,6 +142,7 @@ def swa_pallas(
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="swa_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ) if not interpret else None,

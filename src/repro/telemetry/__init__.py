@@ -15,6 +15,9 @@ weak-scaling claims.  This package makes those numbers first-class:
   lowered HLO is bit-identical with telemetry on or off (pinned by
   ``tests/test_telemetry.py``);
 * :mod:`metrics`  — the paper's ``A_eff``/``T_eff`` convention;
+* :mod:`scopes`   — which program scope (``hide.*``, ``halo.update``)
+  each instruction of a compiled module belongs to, to read a profiler
+  trace phase by phase (:func:`op_scopes`);
 * :mod:`sink`     — structured sinks: a no-op default, an in-memory
   recorder, JSONL metric events, and a Chrome-trace/Perfetto span export
   (load the file at ``ui.perfetto.dev`` or ``chrome://tracing``).
@@ -48,9 +51,10 @@ from .counters import (
 from .flight import FlightRecorder, flight
 from .health import HealthConfig, SolveStatus, watch, watching
 from .metrics import a_eff, t_eff
+from .scopes import PROGRAM_SCOPES, op_scopes
 from .sink import ChromeTraceSink, JsonlSink, MemorySink, NullSink
 from .timers import (
-    Session, current_session, enabled, metric, region, session,
+    Session, current_session, enabled, metric, region, session, trace_span,
 )
 
 
@@ -82,6 +86,7 @@ __all__ = [
     "HealthConfig", "SolveStatus", "watch", "watching",
     "a_eff", "t_eff",
     "ChromeTraceSink", "JsonlSink", "MemorySink", "NullSink",
+    "PROGRAM_SCOPES", "op_scopes",
     "Session", "current_session", "enabled", "metric", "region", "session",
-    "observe",
+    "trace_span", "observe",
 ]

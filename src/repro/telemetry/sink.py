@@ -5,8 +5,7 @@ Events are plain dicts with a ``type`` key:
 * ``{"type": "span",   "name", "ts", "dur", "depth", "rank", ...}``
   — a timed region (seconds, relative to the session start);
 * ``{"type": "metric", "name", "value", "rank", ...}``
-  — a named scalar (e.g. ``t_eff_gbs``);
-* ``{"type": "counter", "name", ...}`` — a counter snapshot.
+  — a named scalar (e.g. ``t_eff_gbs``).
 
 ``MemorySink`` (the session default) records events in order and can
 serialize them two ways: one JSON object per line (:meth:`dump_jsonl`,

@@ -2,9 +2,10 @@
 
 A :class:`Session` owns a sink and a monotonic clock origin; it is
 installed module-wide by the :func:`session` context manager (or
-``Session.start()``).  With no session installed, :func:`region` and
-:func:`metric` cost one falsy check — the hot solve path is untouched
-(``tests/test_telemetry.py`` pins identical lowered HLO).
+``Session.start()``).  With no session installed, :func:`metric` costs
+one falsy check and :func:`region` one profiler annotation besides — the
+hot solve path is untouched (``tests/test_telemetry.py`` pins identical
+lowered HLO).
 
 Regions are nestable and **synced**: JAX dispatch is asynchronous, so a
 bare ``perf_counter`` pair around a jitted call times the dispatch, not
@@ -13,12 +14,19 @@ the value (or the result of the callable) before closing the span.
 Ranks: under the single-controller runtimes used here the host is rank
 ``jax.process_index()``; spans carry it so multi-process traces merge
 into one Perfetto timeline with a row per rank.
+
+Every region is also a ``jax.profiler.TraceAnnotation``, session or
+not: under ``jax.profiler.trace`` it lands on the profiler's host plane,
+on one timeline with the device's ops.  :func:`trace_span` is that
+annotation alone, for per-call spans on a hot path (no session event).
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .sink import MemorySink, NullSink
 
@@ -72,10 +80,6 @@ class Session:
         self.emit({"type": "metric", "name": name, "value": value,
                    "ts": self.now(), "rank": self.rank, **attrs})
 
-    def counter(self, name: str, snapshot: dict, **attrs):
-        self.emit({"type": "counter", "name": name, "rank": self.rank,
-                   **snapshot, **attrs})
-
     # -- lifecycle -----------------------------------------------------
     def start(self) -> "Session":
         global _CURRENT
@@ -126,29 +130,52 @@ def _sync(value):
     jax.block_until_ready(value() if callable(value) else value)
 
 
-@contextlib.contextmanager
 def region(name: str, *, sync=None, **attrs):
     """Time a region; emits a span event to the active session.
 
     ``sync`` — an array/pytree (or a zero-arg callable returning one)
     blocked on before the span closes, so asynchronously dispatched
-    device work is charged to the region that launched it.  No-op (single
-    falsy check, no sync) when no session is active.
+    device work is charged to the region that launched it.  Session or
+    not, the region is a ``TraceAnnotation`` of its name on the
+    profiler's timeline (a no-op while no profiler trace is taken); with
+    no session active that annotation is all it is: no event, no sync.
     """
     s = _CURRENT
     if s is None:
-        yield
-        return
-    s._depth += 1
-    t0 = s.now()
-    try:
-        yield
-        if sync is not None:
-            _sync(sync)
-    finally:
-        s._depth -= 1
-        t1 = s.now()
-        s.span(name, t0, t1 - t0, **attrs)
+        return _TraceAnnotation(name)
+    return _SessionRegion(s, name, sync, attrs)
+
+
+class _SessionRegion:
+    """A region under an active session: the annotation, plus a span
+    event when it closes."""
+
+    __slots__ = ("s", "name", "sync", "attrs", "ann", "t0")
+
+    def __init__(self, s: Session, name: str, sync, attrs: dict):
+        self.s, self.name, self.sync, self.attrs = s, name, sync, attrs
+
+    def __enter__(self):
+        self.ann = _TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.s._depth += 1
+        self.t0 = self.s.now()
+
+    def __exit__(self, exc_type, exc, tb):
+        s = self.s
+        try:
+            if exc_type is None and self.sync is not None:
+                _sync(self.sync)
+        finally:
+            self.ann.__exit__(exc_type, exc, tb)
+            s._depth -= 1
+            s.span(self.name, self.t0, s.now() - self.t0, **self.attrs)
+
+
+# A span on the profiler's timeline only, with no session event, for
+# per-call spans on a hot path: under a microsecond a use while no trace
+# is taken.
+trace_span = _TraceAnnotation
 
 
 def metric(name: str, value, **attrs):
@@ -158,4 +185,4 @@ def metric(name: str, value, **attrs):
 
 
 __all__ = ["Session", "current_session", "enabled", "metric", "region",
-           "session", "MemorySink", "NullSink"]
+           "session", "trace_span", "MemorySink", "NullSink"]

@@ -287,6 +287,123 @@ def test_region_noop_without_session():
         pass  # must not raise, must not require a session
 
 
+def test_region_events_with_and_without_a_session():
+    """With no session a region emits nothing and syncs nothing; under a
+    session it emits one span per region, innermost first, with its depth,
+    rank and attrs, syncing only on a clean exit."""
+    from repro.telemetry import MemorySink, region, session
+
+    synced = []
+
+    def sync():
+        synced.append(1)
+        return np.zeros(1)
+
+    with region("off", sync=sync):
+        pass
+    assert synced == []
+
+    sink = MemorySink()
+    with session(sink=sink):
+        with region("outer", label="x", sync=sync):
+            with region("inner"):
+                pass
+        try:
+            with region("raises", sync=sync):
+                raise KeyError("x")
+        except KeyError:
+            pass
+    assert synced == [1]                      # not on the raising region
+    assert [{k: v for k, v in e.items() if k not in ("ts", "dur")}
+            for e in sink.events] == [
+        {"type": "span", "name": "inner", "depth": 1, "rank": 0},
+        {"type": "span", "name": "outer", "depth": 0, "rank": 0, "label": "x"},
+        {"type": "span", "name": "raises", "depth": 0, "rank": 0},
+    ]
+    inner, outer, _ = sink.events
+    assert outer["ts"] <= inner["ts"] and inner["dur"] <= outer["dur"]
+
+
+def test_regions_and_parallel_calls_reach_the_profiler_trace(tmp_path):
+    """A region, session or not, and each call of a ``grid.parallel``
+    function are host events of the profiler's trace; the wrapper's
+    ``lower`` lowers the program its calls run."""
+    run("""
+import glob
+from jax.profiler import ProfileData
+from repro import telemetry as tele
+from repro.core import init_global_grid
+
+g = init_global_grid(6, 6, 6, dims=(1, 1, 1))
+
+@g.parallel
+def twice(A):
+    return 2 * A
+
+A = g.full(1.0)
+twice(A).block_until_ready()
+assert "multiply" in twice.lower(A).compile().as_text()
+with jax.profiler.trace(%r):
+    with tele.region("tele.off"):
+        twice(A).block_until_ready()
+    with tele.session():
+        with tele.region("tele.on"):
+            twice(A).block_until_ready()
+path, = glob.glob(%r + "/**/*.xplane.pb", recursive=True)
+host = next(p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU")
+names = [e.name for line in host.lines for e in line.events]
+assert names.count("tele.off") == 1 and names.count("tele.on") == 1, names
+assert names.count("grid.parallel.twice") == 2, names
+print("OK")
+""" % (str(tmp_path), str(tmp_path)), ndev=1)
+
+
+def test_op_scopes_name_each_hide_phase():
+    """In the compiled module of a hidden heat step on two ranks, each
+    instruction the program made maps to the innermost of its phases:
+    the shell's and the interior's slices, step arithmetic and writes, and
+    the exchange's permutes under ``halo.update``; what the compiler made
+    itself (copies, hoisted constants) maps to nothing."""
+    out = run(r"""
+import re
+from repro import telemetry as tele
+from repro.apps.heat3d import Heat3D
+
+app = Heat3D(nx=40, ny=12, nz=12, dims=(2, 1, 1), use_kernel="ref")
+T, Ci = app.init_fields()
+text = app._step.lower(T, Ci).compile().as_text()
+scopes = tele.op_scopes(text)
+prims = {s: set() for s in tele.PROGRAM_SCOPES}
+unmapped = set()
+for line in text.splitlines():
+    m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = \S+ ([\w\-]+)\(", line)
+    if m is None:
+        continue
+    name, opcode = m.groups()
+    op = re.search(r'op_name="([^"]*)"', line)
+    parts = op.group(1).split("/") if op else []
+    # a JAX primitive as the last part: the program made it
+    made = len(parts) > 2 and not re.fullmatch(r"[\w\-]+\.\d+", parts[-1])
+    assert (name in scopes) == made, line
+    if not made:
+        unmapped.add(opcode)
+        continue
+    inner = [p for p in parts if p in tele.PROGRAM_SCOPES]
+    assert scopes[name] == inner[-1], line
+    if "hide.exchange" in parts:
+        assert scopes[name] == "halo.update", line
+    prims[scopes[name]].add(parts[-1])
+for phase in ("hide.shell", "hide.interior"):
+    assert {"slice", "scatter", "add", "mul"} <= prims[phase], prims[phase]
+assert "ppermute" in prims["halo.update"], prims["halo.update"]
+assert prims["hide.exchange"] == set()
+assert "copy" in unmapped, unmapped
+print("ok", sorted(unmapped))
+""", ndev=2)
+    assert "ok" in out
+
+
 def test_session_is_reentrant():
     """An inner ``session()`` joins the active one (benchmark harnesses
     open their own session yet compose under ``benchmarks/run.py``'s)."""

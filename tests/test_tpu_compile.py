@@ -9,6 +9,7 @@ import time) and the tests skip where it cannot be described.
 """
 
 import os
+import re
 
 import pytest
 
@@ -90,3 +91,29 @@ def test_face_jacobi_compiles(one_chip):
             use_kernel="pallas"),
         [shape] * 5, one_chip)
     assert "tpu_custom_call" in hlo
+
+
+def test_hidden_heat_step_names_its_kernels_by_phase(topo):
+    """The hidden 256^3 step as ``Heat3D`` builds it: its seven heat
+    kernels are ``stencil3d_heat`` instructions, the six slab launches
+    under ``hide.shell`` and the interior launch under ``hide.interior``."""
+    from repro import telemetry as tele
+    from repro.core.grid import ImplicitGlobalGrid
+    from repro.core.topology import make_grid_mesh
+
+    g = ImplicitGlobalGrid(256, 256, 256, mesh=make_grid_mesh(
+        3, (1, 1, 1), devices=[topo.devices[0]]))
+
+    @g.parallel
+    def dstep(T, Ci):
+        return g.hide(lambda T, Ci: heat_step(
+            T, Ci, 1.0, 1e-6, 0.01, 0.01, 0.01, use_kernel="pallas"),
+            (T, Ci), width=(16, 2, 2))
+
+    field = jax.ShapeDtypeStruct((256,) * 3, jnp.float32, sharding=g.sharding)
+    text = dstep.lower(field, field).compile().as_text()
+    kernels = re.findall(r"%(stencil3d_heat\.\d+) = \S+ custom-call\(", text)
+    scopes = tele.op_scopes(text)
+    assert len(kernels) == 7
+    assert sorted(scopes[k] for k in kernels) == (
+        ["hide.interior"] + ["hide.shell"] * 6)
