@@ -108,7 +108,7 @@ def update_halo(
         A = _an.exchange_in(A, width=width, site="core.halo.update_halo")
         exchanged = []
         for d in dims:
-            if topo.dims[d] == 1 and not topo.periodic[d]:
+            if not topo.exchanges(d):
                 continue  # nothing to exchange
             # Telemetry hook: a pure trace-time Python side effect (no-op
             # unless a counting collector is active) — the lowered program

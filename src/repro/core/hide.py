@@ -15,8 +15,14 @@ does) run the interior fusion between ``collective-permute-start`` and
 
 ``hide_communication(topo, step_fn, inputs, width)`` is semantically
 IDENTICAL to ``update_halo(topo, step_fn(*inputs))`` — a property tested
-bitwise in ``tests/test_hide.py`` — but with the boundary/interior split
-dataflow.
+bitwise in ``tests/test_core_grid.py`` and ``tests/test_hide_contracts.py``
+— but with the boundary/interior split dataflow.
+
+The split follows the dims that exchange (``topo.exchanges(d)``: split
+over more than one rank, or periodic).  Only they get boundary slabs;
+along the others the interior takes the whole extent.  Where no dim
+exchanges (one rank, open boundaries) there is nothing to hide and the
+step is ``step_fn(*inputs)`` itself.
 
 Conventions (matching the usual ParallelStencil step):
 
@@ -52,7 +58,9 @@ def hide_communication(
 
     ``width[d]`` is the boundary-shell thickness along grid dim ``d`` (the
     paper's ``@hide_communication (16, 2, 2)`` tuple), clamped to >= halo
-    so the halo send slabs lie inside the freshly computed shell.
+    so the halo send slabs lie inside the freshly computed shell.  Only
+    the dims that exchange (``topo.exchanges(d)``) get a shell; along the
+    others the interior spans the whole extent.
     """
     inputs = tuple(jnp.asarray(A) for A in inputs)
     ref = inputs[0]
@@ -66,7 +74,8 @@ def hide_communication(
         width = (width,) * nd
     w = tuple(max(int(wd), h) for wd in width)
     shape = ref.shape
-    for d in range(nd):
+    ex = tuple(d for d in range(nd) if topo.exchanges(d))
+    for d in ex:
         if shape[d] < 2 * (w[d] + h):
             raise ValueError(
                 f"local extent {shape[d]} too small for shell width {w[d]} + halo {h}"
@@ -81,40 +90,57 @@ def hide_communication(
     # metadata, so a profiler trace can be read phase by phase
     # (``repro.telemetry.op_scopes``).  Scopes are trace-time only.
 
-    # ---- 1. boundary shell: two face slabs per grid dim ----------------
-    # Slabs span the full extent of the other dims; corners are recomputed
-    # by later faces (same values — harmless).
-    outs = None
-    with jax.named_scope("hide.shell"):
-        for d in range(nd):
-            n = shape[d]
-            wd = w[d]
-            lo = run(tuple(A[_slc(nd, d, 0, 2 * h + wd)] for A in inputs))
-            hi = run(tuple(A[_slc(nd, d, n - 2 * h - wd, n)] for A in inputs))
-            if outs is None:
-                # Pass-through convention: output k starts as old inputs[k].
-                outs = [inputs[k] for k in range(len(lo))]
-            sl = _slc(nd, d, h, h + wd)  # valid region, slab-local == face-global (low)
+    if not ex:
+        # Nothing exchanges (one rank, open boundaries): the interior is
+        # the whole field and there is nothing to hide, so the step is the
+        # plain one, with no slices and no writes back into the field.
+        with jax.named_scope("hide.interior"):
+            outs = list(run(inputs))
+        with jax.named_scope("hide.exchange"):
+            updated = update_halo(topo, *outs, width=h)  # exchanges nothing
+        outs = list(updated) if isinstance(updated, tuple) else [updated]
+    else:
+        # ---- 1. boundary shell: two face slabs per exchanging dim -------
+        # Slabs span the full extent of the other dims; corners are
+        # recomputed by later faces (same values — harmless).
+        # Pass-through convention: output k starts as old inputs[k].
+        outs = None
+        with jax.named_scope("hide.shell"):
+            for d in ex:
+                n = shape[d]
+                wd = w[d]
+                lo = run(tuple(A[_slc(nd, d, 0, 2 * h + wd)] for A in inputs))
+                hi = run(tuple(A[_slc(nd, d, n - 2 * h - wd, n)] for A in inputs))
+                if outs is None:
+                    outs = [inputs[k] for k in range(len(lo))]
+                sl = _slc(nd, d, h, h + wd)  # valid region, slab-local == face-global (low)
+                for k in range(len(outs)):
+                    outs[k] = outs[k].at[sl].set(lo[k][sl])
+                    outs[k] = outs[k].at[_slc(nd, d, n - h - wd, n - h)].set(
+                        hi[k][_slc(nd, d, h, h + wd)]
+                    )
+
+        # ---- 2. halo exchange — depends only on the boundary shell -------
+        with jax.named_scope("hide.exchange"):
+            updated = update_halo(topo, *outs, width=h)
+        outs = list(updated) if isinstance(updated, tuple) else [updated]
+
+        # ---- 3. interior — independent of the collectives (overlappable) -
+        # Along an exchanging dim the interior is [w, n-w) and its valid
+        # part [w+h, n-w-h); along any other dim it is the whole extent,
+        # and the output's ring there passes through the input's values,
+        # so the write covers whole planes.
+        def span(d, lo, hi):
+            return slice(lo, hi) if d in ex else slice(None)
+
+        with jax.named_scope("hide.interior"):
+            int_in = tuple(A[tuple(span(d, w[d], shape[d] - w[d]) for d in range(nd))]
+                           for A in inputs)
+            int_out = run(int_in)
+            sl_local = tuple(span(d, h, shape[d] - 2 * w[d] - h) for d in range(nd))
+            sl_global = tuple(span(d, w[d] + h, shape[d] - w[d] - h) for d in range(nd))
             for k in range(len(outs)):
-                outs[k] = outs[k].at[sl].set(lo[k][sl])
-                outs[k] = outs[k].at[_slc(nd, d, n - h - wd, n - h)].set(
-                    hi[k][_slc(nd, d, h, h + wd)]
-                )
-
-    # ---- 2. halo exchange — depends only on the boundary shell ---------
-    with jax.named_scope("hide.exchange"):
-        updated = update_halo(topo, *outs, width=h)
-    outs = list(updated) if isinstance(updated, tuple) else [updated]
-
-    # ---- 3. interior — independent of the collectives (overlappable) ---
-    with jax.named_scope("hide.interior"):
-        int_in = tuple(A[tuple(slice(w[d], shape[d] - w[d]) for d in range(nd))]
-                       for A in inputs)
-        int_out = run(int_in)
-        sl_local = tuple(slice(h, (shape[d] - 2 * w[d]) - h) for d in range(nd))
-        sl_global = tuple(slice(w[d] + h, shape[d] - w[d] - h) for d in range(nd))
-        for k in range(len(outs)):
-            outs[k] = outs[k].at[sl_global].set(int_out[k][sl_local])
+                outs[k] = outs[k].at[sl_global].set(int_out[k][sl_local])
 
     # Analyzer contract: semantically this IS ``update_halo(step(...))``
     # (bitwise-pinned in tests) — the exchanged planes mirror the
@@ -181,7 +207,7 @@ def hide_apply(
                           contract=True)
     out = op_fn(ub, *extra)  # stale halos: wrong only on the inner shell
     for d in range(nd):
-        if topo.dims[d] == 1 and not topo.periodic[d]:
+        if not topo.exchanges(d):
             # No exchange along d: u2 == u there, and every cell needing
             # fresh halos of OTHER dims lies in those dims' shells.
             continue

@@ -98,6 +98,11 @@ class CartesianTopology:
             1 if ax is None else self.mesh.shape[ax] for ax in self.axes
         )
 
+    def exchanges(self, dim: int) -> bool:
+        """Whether grid dim ``dim`` has a halo to exchange: it is split
+        over more than one rank, or it wraps around."""
+        return self.dims[dim] > 1 or self.periodic[dim]
+
     def spec(self, extra_leading: int = 0) -> P:
         """PartitionSpec sharding grid dims over their mesh axes."""
         return P(*([None] * extra_leading), *self.axes)

@@ -8,6 +8,7 @@ Nothing executes.  The topology is described inside a fixture (never at
 import time) and the tests skip where it cannot be described.
 """
 
+import math
 import os
 import re
 
@@ -44,8 +45,8 @@ def _compiled_hlo(fn, shapes, sharding, dtype=jnp.float32):
 
 
 # Heat3D at 256^3 local: the plain step, and the shapes
-# hide_communication(width=(16, 2, 2)) launches: x/y/z boundary slabs of
-# 2h + w rows and the (n - 2w) interior.
+# hide_communication(width=(16, 2, 2)) launches on a mesh that splits
+# every dim: x/y/z boundary slabs of 2h + w rows and the (n - 2w) interior.
 HEAT_SHAPES = [(256, 256, 256), (18, 256, 256), (256, 4, 256),
                (256, 256, 4), (224, 252, 252)]
 
@@ -93,16 +94,29 @@ def test_face_jacobi_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def test_hidden_heat_step_names_its_kernels_by_phase(topo):
-    """The hidden 256^3 step as ``Heat3D`` builds it: its seven heat
-    kernels are ``stencil3d_heat`` instructions, the six slab launches
-    under ``hide.shell`` and the interior launch under ``hide.interior``."""
+# (mesh dims, {scope: kernel shapes}) of the hidden 256^3 step with
+# width=(16, 2, 2): one chip has nothing to exchange and runs the plain
+# step; v5e:2x2 as (2, 2, 1) launches x and y slabs and an interior that
+# spans z whole.
+HIDDEN_PHASES = [
+    ((1, 1, 1), {"hide.interior": [(256, 256, 256)]}),
+    ((2, 2, 1), {"hide.shell": [(18, 256, 256)] * 2 + [(256, 4, 256)] * 2,
+                 "hide.interior": [(224, 252, 256)]}),
+]
+
+
+@pytest.mark.parametrize("dims,phases", HIDDEN_PHASES)
+def test_hidden_heat_step_names_its_kernels_by_phase(topo, dims, phases):
+    """The hidden 256^3 step as ``Heat3D`` builds it: its heat kernels
+    are ``stencil3d_heat`` instructions, the slab launches of the
+    exchanging dims under ``hide.shell`` and the interior launch under
+    ``hide.interior``."""
     from repro import telemetry as tele
     from repro.core.grid import ImplicitGlobalGrid
     from repro.core.topology import make_grid_mesh
 
     g = ImplicitGlobalGrid(256, 256, 256, mesh=make_grid_mesh(
-        3, (1, 1, 1), devices=[topo.devices[0]]))
+        3, dims, devices=topo.devices[:math.prod(dims)]))
 
     @g.parallel
     def dstep(T, Ci):
@@ -110,10 +124,15 @@ def test_hidden_heat_step_names_its_kernels_by_phase(topo):
             T, Ci, 1.0, 1e-6, 0.01, 0.01, 0.01, use_kernel="pallas"),
             (T, Ci), width=(16, 2, 2))
 
-    field = jax.ShapeDtypeStruct((256,) * 3, jnp.float32, sharding=g.sharding)
+    field = jax.ShapeDtypeStruct(g.stacked_shape, jnp.float32,
+                                 sharding=g.sharding)
     text = dstep.lower(field, field).compile().as_text()
-    kernels = re.findall(r"%(stencil3d_heat\.\d+) = \S+ custom-call\(", text)
+    kernels = re.findall(
+        r"%(stencil3d_heat\.\d+) = f32\[([\d,]+)\]\S* custom-call\(", text)
     scopes = tele.op_scopes(text)
-    assert len(kernels) == 7
-    assert sorted(scopes[k] for k in kernels) == (
-        ["hide.interior"] + ["hide.shell"] * 6)
+    found = {}
+    for name, shape in kernels:
+        found.setdefault(scopes[name], []).append(
+            tuple(int(n) for n in shape.split(",")))
+    assert {k: sorted(v) for k, v in found.items()} == {
+        k: sorted(v) for k, v in phases.items()}
