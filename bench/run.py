@@ -9,9 +9,10 @@ cell's app from its configuration and its traffic mix, makes the inputs on
 the devices from ``--seed``, warms up the cell's own shapes (set-up, timed
 from the start of the process), then drives the app for ``--seconds``
 seconds.  ``--trace 1`` instead takes a profiler trace of a steady stretch
-and reads the per-layer metrics from it.  Once the window has closed,
-the device's peak memory has been read and the program's state is freed,
-the answers kept from the window are compared with the plain reference.
+and reads the per-layer metrics from it.  Once the window has closed and
+the device's peak memory has been read, the app finishes the answers it
+compares (``finish``: steps the window did not reach, untimed); once the
+program's state is freed, they are compared with the plain reference.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (with ``--trace 1``
@@ -264,6 +265,7 @@ def main(argv=None) -> int:
     entries = cell.per_layer if args.trace else cell.end_to_end
     metrics = read_metrics(entries, run)
 
+    app.finish()
     app.release()
     compared, readings = app.check()
     for k, v in readings.items():

@@ -13,7 +13,8 @@ from _sub import BENCH, json_lines, run
 READINGS = os.path.join(BENCH, "tools", "readings.py")
 
 
-@pytest.mark.parametrize("cell", ["heat3d-256.plain", "heat3d-256.hide"])
+@pytest.mark.parametrize("cell", ["heat3d-256.plain", "heat3d-256.hide",
+                                  "heat3d-512.plain"])
 def test_control_reads_above_the_limit_and_the_program_below(cell):
     rows = json_lines(run(READINGS, "--workload", cell, "--seeds", 7,
                           "--control-seeds", 8, "--seconds", 1,

@@ -7,14 +7,21 @@ instruction name, so they give the exact join that ``harness.scopes``
 reaches by order."""
 
 import dataclasses
+import json
+import math
 import os
 import re
+import subprocess
+import sys
 import types
 
 import pytest
 
+from _sub import BENCH
 from harness import scopes, spec
 from harness import trace as tr
+
+ROOT = os.path.dirname(BENCH)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 FIXTURE = os.path.join(DATA, "heat3d-256.hide.scoped.xplane.pb")
@@ -160,8 +167,35 @@ def test_untraced_runs_and_programs_without_lower_read_none():
     assert scopes.program_text(app) is None
 
 
+# The hide cell's rehearsal app on ``dims``, built in a child process that
+# has as many CPU devices as the dims ask for: the scopes of its program.
+CHILD = """
+import dataclasses, json, sys
+from harness import scopes, spec
+dims = json.loads(sys.argv[1])
+cell = spec.find_cell("heat3d-256.hide")
+cell = dataclasses.replace(cell, config=dict(cell.config, dims=dims))
+app = spec.load_module("apps", cell.app).App(cell, 1, True)
+text = scopes.program_text(app)
+print(json.dumps(sorted(set(scopes.instruction_scopes(text).values()))))
+"""
+
+
+def rehearsal_scopes(dims):
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count="
+                         f"{math.prod(dims)}",
+               PYTHONPATH=os.pathsep.join([BENCH, os.path.join(ROOT, "src")]))
+    proc = subprocess.run([sys.executable, "-c", CHILD, json.dumps(dims)],
+                          capture_output=True, text=True, timeout=600,
+                          env=env, check=True)
+    return set(json.loads(proc.stdout.strip().splitlines()[-1]))
+
+
 def test_program_text_of_a_rehearsal_app_carries_the_scopes():
-    cell = spec.find_cell("heat3d-256.hide")
-    app = spec.load_module("apps", cell.app).App(cell, 1, True)
-    text = scopes.program_text(app)
-    assert "hide.shell" in set(scopes.instruction_scopes(text).values())
+    """Where a dim exchanges (two devices along x) the hidden step has a
+    shell; on one device nothing exchanges and the step is the plain one,
+    under ``hide.interior`` alone."""
+    assert "hide.shell" in rehearsal_scopes([2, 1, 1])
+    one = rehearsal_scopes([1, 1, 1])
+    assert "hide.interior" in one and "hide.shell" not in one

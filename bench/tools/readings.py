@@ -4,8 +4,8 @@
         [--control-seeds 4 5 6] [--fault NAME ...] [--seconds S] [--rehearsal]
 
 For each ``--seeds`` seed it runs the program as a benchmark run does
-(build, warm up, a window of ``--seconds``, release, compare) and prints
-the numbers compared.  For each ``--control-seeds`` seed it puts the plain
+(build, warm up, a window of ``--seconds``, finish, release, compare) and
+prints the numbers compared.  For each ``--control-seeds`` seed it puts the plain
 reference, computed one precision below the configuration's (bfloat16
 for float32), in the program's place, and prints what the same comparison
 reads.  ``--fault`` breaks the timed path in one way (see ``FAULTS``) and
@@ -58,13 +58,15 @@ def _heat_altered(app):
 
 def _heat_coarse_increment(app):
     """The program's step with its increment rounded to bfloat16: a
-    lower precision in part of the update only."""
+    lower precision in part of the update only.  The rounding is a
+    ``reduce_precision`` to bfloat16's bits: a round trip through
+    ``astype(bfloat16)`` is dropped by the TPU's compiler, which may keep
+    excess precision, and would leave the step sound."""
     import jax
-    import jax.numpy as jnp
 
     step = app.program._step
-    coarse = jax.jit(lambda T, new: T + (new - T).astype(jnp.bfloat16)
-                     .astype(T.dtype))
+    coarse = jax.jit(lambda T, new: T + jax.lax.reduce_precision(
+        new - T, exponent_bits=8, mantissa_bits=7))
     app.timed_step = lambda T, Ci: coarse(T, step(T, Ci))
 
 
@@ -100,6 +102,7 @@ def one(cell, App, seed, rehearsal, seconds, kind, fault=None):
         FAULTS[cell.app][fault](app)
     app.warmup()
     win = app.window(seconds)
+    app.finish()
     app.release()
     compared, each = app.check()
     return {"kind": kind if fault is None else f"fault.{fault}",
